@@ -102,6 +102,9 @@ def figure_cli(
         else:
             request_host_devices(max_clients(args.fast))
     from repro import telemetry
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     t0 = time.perf_counter()
     with telemetry.span("benchmark", figure=name, backend=args.backend,

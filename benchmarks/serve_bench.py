@@ -36,7 +36,6 @@ def run(fast: bool = False, dataset: str | None = None, seed: int = 0,
         GraphInferenceServer,
         MicroBatcher,
         Query,
-        resolve_serving_engine,
     )
 
     dataset = dataset or ("tiny" if fast else "cora_like")
@@ -62,7 +61,6 @@ def run(fast: bool = False, dataset: str | None = None, seed: int = 0,
         ]
         arrivals = list(np.cumsum(rng.exponential(1.0 / qps, size=num_queries)))
         for engine in engines:
-            resolved, _note = resolve_serving_engine(engine)
             server = GraphInferenceServer(
                 params, model_cfg, g, num_clients=clients, engine=engine,
             )
@@ -76,7 +74,7 @@ def run(fast: bool = False, dataset: str | None = None, seed: int = 0,
                 rows.append({
                     "dataset": dataset,
                     "engine_requested": engine,
-                    "engine": resolved,
+                    "engine": engine,
                     "clients": clients,
                     "max_batch_size": bs,
                     "queries": int(s["queries"]),

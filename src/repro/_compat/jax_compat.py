@@ -1,31 +1,12 @@
 """jax API compatibility helpers."""
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Replication checking is disabled in both cases (our bodies mix
-    replicated and per-shard collectives).
-    """
-    if hasattr(jax, "shard_map"):
-        params = inspect.signature(jax.shard_map).parameters
-        check_kw = (
-            {"check_vma": False} if "check_vma" in params
-            else {"check_rep": False} if "check_rep" in params
-            else {}
-        )
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **check_kw
-        )
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with replication checking off: our bodies mix
+    replicated and per-shard collectives."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

@@ -170,10 +170,16 @@ class DirectEngine(Engine):
 class KernelEngine(Engine):
     """Fused Pallas polynomial-attention kernel (see repro/kernels)."""
 
-    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
-        from repro.kernels import ops as kernel_ops  # lazy: pallas import
+    def __init__(self, cfg):
+        super().__init__(cfg)
+        # Imported on construction, so a missing Pallas stack fails here
+        # and never runs some other engine in the kernel's place.
+        from repro.kernels import ops
 
-        return kernel_ops.cheb_attn_layer(
+        self._ops = ops
+
+    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
+        return self._ops.cheb_attn_layer(
             params, coeffs, h, nbr_idx, nbr_mask,
             basis=self.cfg.basis, domain=self.cfg.domain, concat=concat,
         )

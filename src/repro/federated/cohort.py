@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict
+from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
@@ -56,7 +57,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
-from repro.core.gat import masked_accuracy
 from repro.federated.aggregation import (
     RunningAggregate,
     fedadam_update,
@@ -227,9 +227,10 @@ class _CohortStager:
 def make_vmap_cohort_step(cfg, local_update: Callable, K: int) -> Callable:
     """One cohort on vmap lanes.
 
-    (gparams, agg, opt_slice, nb, tr, ids, w, lam, sel_row, t)
+    (gparams, agg, opt_slice, data, nb, tr, ids, w, lam, sel_row, t)
       -> (agg', new_opt_slice)
 
+    ``data`` is the graph's device arrays (``trainer.build_forward``).
     ``nb`` is stacked (lanes, N, B) for per-client visibility (distgat) or
     a single shared (N, B) mask otherwise (broadcast via in_axes=None, so
     no per-lane copy exists).
@@ -240,11 +241,12 @@ def make_vmap_cohort_step(cfg, local_update: Callable, K: int) -> Callable:
     mask_base = mask_base_key(cfg.seed)
 
     @jax.jit
-    def step(gparams, agg, opt_slice, nb, tr, ids, w, lam, sel_row, t):
+    def step(gparams, agg, opt_slice, data, nb, tr, ids, w, lam, sel_row, t):
         noise_keys = jax.vmap(lambda c: client_round_key(noise_base, t, c))(ids)
         stacked, new_opt = jax.vmap(
-            local_update, in_axes=(None, 0, 0 if per_client_nb else None, 0, 0)
-        )(gparams, opt_slice, nb, tr, noise_keys)
+            local_update,
+            in_axes=(None, 0, None, 0 if per_client_nb else None, 0, 0),
+        )(gparams, opt_slice, data, nb, tr, noise_keys)
         if priv.secure_agg:
             stacked = jax.vmap(
                 lambda p, c: add_client_mask(
@@ -269,13 +271,13 @@ def make_shard_cohort_step(cfg, local_update: Callable, mesh, K: int) -> Callabl
     noise_base = noise_base_key(cfg.seed)
     mask_base = mask_base_key(cfg.seed)
 
-    def body(gparams, agg, opt_slice, nb, tr, ids, w, lam, sel_row, t):
+    def body(gparams, agg, opt_slice, data, nb, tr, ids, w, lam, sel_row, t):
         cid = ids[0]
         wl = w[0]
         opt1 = jax.tree.map(lambda x: x[0], opt_slice)
         nbm = nb[0] if per_client_nb else nb
         noise_key = client_round_key(noise_base, t, cid)
-        params, new_opt = local_update(gparams, opt1, nbm, tr[0], noise_key)
+        params, new_opt = local_update(gparams, opt1, data, nbm, tr[0], noise_key)
         if priv.secure_agg:
             params = add_client_mask(
                 mask_base, t, cid, sel_row, params, priv.mask_scale
@@ -297,7 +299,7 @@ def make_shard_cohort_step(cfg, local_update: Callable, mesh, K: int) -> Callabl
         shard_map(
             body,
             mesh=mesh,
-            in_specs=(P(), P(), lanes, lanes if per_client_nb else P(),
+            in_specs=(P(), P(), lanes, P(), lanes if per_client_nb else P(),
                       lanes, lanes, lanes, P(), P(), P()),
             out_specs=(P(), lanes),
         )
@@ -315,11 +317,12 @@ def make_vmap_collect_step(cfg, local_update: Callable, K: int) -> Callable:
     noise_base = noise_base_key(cfg.seed)
 
     @jax.jit
-    def step(gparams, opt_slice, nb, tr, ids, t):
+    def step(gparams, opt_slice, data, nb, tr, ids, t):
         noise_keys = jax.vmap(lambda c: client_round_key(noise_base, t, c))(ids)
         return jax.vmap(
-            local_update, in_axes=(None, 0, 0 if per_client_nb else None, 0, 0)
-        )(gparams, opt_slice, nb, tr, noise_keys)
+            local_update,
+            in_axes=(None, 0, None, 0 if per_client_nb else None, 0, 0),
+        )(gparams, opt_slice, data, nb, tr, noise_keys)
 
     return step
 
@@ -336,12 +339,12 @@ def make_shard_collect_step(cfg, local_update: Callable, mesh, K: int) -> Callab
     per_client_nb = cfg.method == "distgat"
     noise_base = noise_base_key(cfg.seed)
 
-    def body(gparams, opt_slice, nb, tr, ids, t):
+    def body(gparams, opt_slice, data, nb, tr, ids, t):
         cid = ids[0]
         opt1 = jax.tree.map(lambda x: x[0], opt_slice)
         nbm = nb[0] if per_client_nb else nb
         noise_key = client_round_key(noise_base, t, cid)
-        params, new_opt = local_update(gparams, opt1, nbm, tr[0], noise_key)
+        params, new_opt = local_update(gparams, opt1, data, nbm, tr[0], noise_key)
         return (
             jax.tree.map(lambda x: x[None], params),
             jax.tree.map(lambda x: x[None], new_opt),
@@ -352,7 +355,7 @@ def make_shard_collect_step(cfg, local_update: Callable, mesh, K: int) -> Callab
         shard_map(
             body,
             mesh=mesh,
-            in_specs=(P(), lanes, lanes if per_client_nb else P(),
+            in_specs=(P(), lanes, P(), lanes if per_client_nb else P(),
                       lanes, lanes, P()),
             out_specs=(lanes, lanes),
         )
@@ -437,6 +440,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
     cross-committed-device friction.
     """
     from repro.federated.trainer import (
+        accuracies,
         build_forward,
         build_result,
         make_local_update,
@@ -451,7 +455,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
     k_pack, k_init = jax.random.split(key)
     part = dirichlet_partition(g.labels, K, cfg.beta, cfg.seed)
 
-    init_fn, forward = build_forward(cfg, g, k_pack)
+    init_fn, forward, data = build_forward(cfg, g, k_pack)
     global_params = jax.device_get(init_fn(k_init))
 
     cohort_report: Dict[str, Any] = {
@@ -493,13 +497,13 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
             raise ValueError("mesh given but backend is 'vmap'")
         lanes = cohort_lanes(cfg, backend)
 
-    labels = jnp.asarray(g.labels)
-    nbr_mask = jnp.asarray(g.nbr_mask)
-    val_mask = jnp.asarray(g.val_mask)
-    test_mask = jnp.asarray(g.test_mask)
+    if backend == "shard_map":
+        # Replicated on the lanes mesh once, not re-sent every cohort step.
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
+        data = jax.device_put(data, NamedSharding(mesh, P()))
     protocol = cfg.privacy.secure_agg_protocol
-    local_update = make_local_update(make_loss_fn(forward, labels), cfg)
+    local_update = make_local_update(make_loss_fn(forward), cfg)
     if backend == "shard_map":
         step = (
             make_shard_collect_step(cfg, local_update, mesh, K)
@@ -513,14 +517,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
             else make_vmap_cohort_step(cfg, local_update, K)
         )
 
-    @jax.jit
-    def evaluate(params):
-        logits = forward(params, nbr_mask)
-        return (
-            masked_accuracy(logits, labels, val_mask),
-            masked_accuracy(logits, labels, test_mask),
-        )
-
+    evaluate = jax.jit(partial(accuracies, forward))
     server_apply = jax.jit(
         lambda gp, mean, srv: fedadam_update(gp, mean, srv, cfg.server_lr)
     )
@@ -548,7 +545,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
         g, part, lanes, per_client_nb=cfg.method == "distgat",
         capacity=max(8, 2 * plans[0].ids.shape[0]),
     )
-    shared_nb = np.asarray(g.nbr_mask)
+    shared_nb = data["nbr_mask"]
 
     val_curve: List[float] = []
     test_curve: List[float] = []
@@ -597,7 +594,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
                     if protocol:
                         with telemetry.span("step"):
                             stacked, new_opt = step(
-                                g_round, opt_slice,
+                                g_round, opt_slice, data,
                                 nb if nb is not None else shared_nb, tr,
                                 ids, t_arr,
                             )
@@ -625,7 +622,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
                     else:
                         with telemetry.span("step"):
                             agg, new_opt = step(
-                                g_round, agg, opt_slice,
+                                g_round, agg, opt_slice, data,
                                 nb if nb is not None else shared_nb, tr,
                                 ids, w, jnp.asarray(plan.staleness[c], jnp.float32),
                                 plan.sel_row, t_arr,
@@ -656,7 +653,7 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
                 else:
                     global_params = jax.device_get(mean) if protocol else mean
             with telemetry.span("evaluate"):
-                va, ta = evaluate(global_params)
+                va, ta = evaluate(global_params, data)
         val_curve.append(float(va))
         test_curve.append(float(ta))
         if traced and priv.dp_enabled:

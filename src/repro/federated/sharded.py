@@ -62,7 +62,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import telemetry
 from repro._compat.jax_compat import shard_map
-from repro.core.gat import masked_accuracy
 from repro.federated.aggregation import fedadam_update
 from repro.federated.partition import (
     ClientSubgraph,
@@ -74,6 +73,7 @@ from repro.federated.partition import (
 )
 from repro.federated.trainer import (
     FederatedConfig,
+    accuracies,
     build_forward,
     build_result,
     client_masks,
@@ -227,7 +227,7 @@ def _run_shard_map(g: Graph, cfg: FederatedConfig, mesh: Mesh | None = None) -> 
     k_pack, k_init = jax.random.split(key)
     part = dirichlet_partition(g.labels, K, cfg.beta, cfg.seed)
 
-    init_fn, forward = build_forward(cfg, g, k_pack)
+    init_fn, forward, data = build_forward(cfg, g, k_pack)
     global_params = init_fn(k_init)
 
     if cfg.rounds == 0:
@@ -255,23 +255,21 @@ def _run_shard_map(g: Graph, cfg: FederatedConfig, mesh: Mesh | None = None) -> 
         sel_full = _put_global(mesh, P(), sel)
         global_params = _replicate_tree(mesh, global_params)
         server_state = _replicate_tree(mesh, server_state)
+        data = _replicate_tree(mesh, data)
     else:
         # Single-process: plain host arrays, exactly the pre-existing path
         # (jit places them), keeping single-host runs bit-identical.
         nb_masks, tr_masks = client_masks(cfg, g, part)
         sel_sharded = sel_full = jnp.asarray(sel)
+        data = jax.device_put(data, NamedSharding(mesh, P()))
 
-    labels = jnp.asarray(g.labels)
-    nbr_mask = jnp.asarray(g.nbr_mask)
-    val_mask = jnp.asarray(g.val_mask)
-    test_mask = jnp.asarray(g.test_mask)
-
-    local_update = make_local_update(make_loss_fn(forward, labels), cfg)
+    local_update = make_local_update(make_loss_fn(forward), cfg)
     priv = cfg.privacy
     noise_base = noise_base_key(cfg.seed)
     mask_base = mask_base_key(cfg.seed)
 
-    def shard_body(nb_masks_s, tr_masks_s, sel_s, sel_full, gparams, srv_state):
+    def shard_body(nb_masks_s, tr_masks_s, sel_s, sel_full, gparams, srv_state,
+                   data):
         """Runs on one shard = one client. Leading client axis is size 1.
 
         ``sel_full`` is the replicated (rounds, K) CS(t) table: each shard
@@ -289,7 +287,7 @@ def _run_shard_map(g: Graph, cfg: FederatedConfig, mesh: Mesh | None = None) -> 
             gp, opt, srv = carry
             noise_key = client_round_key(noise_base, t, cid)
             local_params, new_opt = local_update(
-                gp, opt, nb_mask, tr_mask, noise_key
+                gp, opt, data, nb_mask, tr_mask, noise_key
             )
             if priv.secure_agg:
                 # Ship a masked update: the same deterministic pairwise
@@ -316,11 +314,7 @@ def _run_shard_map(g: Graph, cfg: FederatedConfig, mesh: Mesh | None = None) -> 
             # forward is identical on every shard — run it on shard 0 only
             # and broadcast the two scalars with a psum.
             def do_eval(_):
-                logits = forward(new_global, nbr_mask)
-                return (
-                    masked_accuracy(logits, labels, val_mask),
-                    masked_accuracy(logits, labels, test_mask),
-                )
+                return accuracies(forward, new_global, data)
 
             def skip_eval(_):
                 return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
@@ -344,7 +338,8 @@ def _run_shard_map(g: Graph, cfg: FederatedConfig, mesh: Mesh | None = None) -> 
         shard_map(
             shard_body,
             mesh=mesh,
-            in_specs=(spec_clients, spec_clients, P(None, "clients"), P(), P(), P()),
+            in_specs=(spec_clients, spec_clients, P(None, "clients"), P(), P(), P(),
+                      P()),
             out_specs=(P(), P(), P()),
         )
     )
@@ -352,7 +347,8 @@ def _run_shard_map(g: Graph, cfg: FederatedConfig, mesh: Mesh | None = None) -> 
     # exist on this path — a single span covers the whole scan.
     with telemetry.span("rounds_scan", rounds=cfg.rounds, backend="shard_map"):
         gp, vas, tas = fn(
-            nb_masks, tr_masks, sel_sharded, sel_full, global_params, server_state
+            nb_masks, tr_masks, sel_sharded, sel_full, global_params,
+            server_state, data,
         )
         vas, tas = np.asarray(vas), np.asarray(tas)
     val_curve = [float(x) for x in np.asarray(vas)]

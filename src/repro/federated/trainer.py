@@ -29,6 +29,7 @@ from __future__ import annotations
 import math
 import time
 from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -36,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
-from repro.core.fedgat_model import FedGAT, FedGATConfig
+from repro.core.fedgat_model import FedGAT, FedGATConfig, layered_forward
 from repro.core.gat import masked_accuracy, masked_cross_entropy
 from repro.core.gcn import gcn_forward_nbr, init_gcn_params, normalized_nbr_coeffs
 from repro.federated import comm as comm_mod
@@ -123,17 +124,36 @@ def method_model_config(cfg: FederatedConfig) -> FedGATConfig:
     return cfg.model
 
 
+def graph_data(g: Graph) -> Dict[str, Array]:
+    """The graph's device arrays every jitted step takes as an argument."""
+    return {
+        "h": jnp.asarray(g.features),
+        "nbr_idx": jnp.asarray(g.nbr_idx),
+        "nbr_mask": jnp.asarray(g.nbr_mask),
+        "labels": jnp.asarray(g.labels),
+        "val_mask": jnp.asarray(g.val_mask),
+        "test_mask": jnp.asarray(g.test_mask),
+    }
+
+
 def build_forward(
     cfg: FederatedConfig, g: Graph, key: Array
-) -> Tuple[Callable, Callable]:
-    """Returns (init_fn, forward(params, nbr_mask) -> logits).
+) -> Tuple[Callable, Callable, Dict[str, Any]]:
+    """Returns (init_fn, forward(params, data, nbr_mask) -> logits, data).
 
-    For fedgat/distgat this wraps a :class:`FedGAT` facade (coefficients
+    ``data`` is :func:`graph_data` plus what the method's forward reads
+    (series coefficients and the one-shot pack, or the GCN coefficients).
+    Every jitted step takes it as an argument: closed over, the graph would
+    be baked into each compiled program as constants, which at 1e5 nodes
+    makes compiles take minutes.
+
+    For fedgat/distgat this builds a :class:`FedGAT` facade (coefficients
     computed once; the one-shot pack communicated here, under ``key``).
     With ``privacy.pack_noise_multiplier > 0`` the stored pack is replaced
     by its noised release (privacy/pack_dp.py) — the one-shot Gaussian
     mechanism on the only raw-feature-derived payload that leaves a client.
     """
+    data = graph_data(g)
     if cfg.method in ("fedgat", "distgat"):
         model = FedGAT(method_model_config(cfg))
         model.precommunicate(key, g)
@@ -149,29 +169,31 @@ def build_forward(
             )
             model.pack = noisy_pack(
                 pack_noise_key(cfg.seed), model.pack,
-                jnp.asarray(g.features), cfg.privacy.pack_noise_multiplier,
+                data["h"], cfg.privacy.pack_noise_multiplier,
                 granularity=granularity, node_influence=influence,
             )
+        data.update(coeffs=model.coeffs, pack=model.pack)
 
         def init_fn(k):
             return model.init(k, g)
 
-        def forward(params, nb_mask):
-            return model.apply(params, g, nb_mask)
+        def forward(params, data, nb_mask):
+            return layered_forward(
+                model.engine, params, data["coeffs"], data["pack"],
+                data["h"], data["nbr_idx"], nb_mask,
+            )
 
-        return init_fn, forward
+        return init_fn, forward, data
     if cfg.method == "fedgcn":
-        h = jnp.asarray(g.features)
-        nbr_idx = jnp.asarray(g.nbr_idx)
-        coef = jnp.asarray(normalized_nbr_coeffs(g.nbr_idx, g.nbr_mask))
+        data["coef"] = jnp.asarray(normalized_nbr_coeffs(g.nbr_idx, g.nbr_mask))
 
         def init_fn(k):
             return init_gcn_params(k, g.feature_dim, cfg.gcn_hidden, g.num_classes)
 
-        def forward(params, nb_mask):  # nb_mask unused: aggregates are exact
-            return gcn_forward_nbr(params, h, nbr_idx, coef)
+        def forward(params, data, nb_mask):  # nb_mask unused: aggregates are exact
+            return gcn_forward_nbr(params, data["h"], data["nbr_idx"], data["coef"])
 
-        return init_fn, forward
+        return init_fn, forward, data
     raise ValueError(f"unknown federated method {cfg.method!r}")
 
 
@@ -187,14 +209,26 @@ def client_masks(cfg: FederatedConfig, g: Graph, part: Partition):
     return nb_masks, jnp.asarray(client_train_masks(g, part))
 
 
-def make_loss_fn(forward: Callable, labels: Array) -> Callable:
+def make_loss_fn(forward: Callable) -> Callable:
     """Client objective shared by both backends: masked CE on the client's
     training labels under its edge-visibility mask."""
 
-    def loss_fn(params, nb_mask, tr_mask):
-        return masked_cross_entropy(forward(params, nb_mask), labels, tr_mask)
+    def loss_fn(params, data, nb_mask, tr_mask):
+        return masked_cross_entropy(
+            forward(params, data, nb_mask), data["labels"], tr_mask
+        )
 
     return loss_fn
+
+
+def accuracies(forward: Callable, params, data) -> Tuple[Array, Array]:
+    """(val, test) accuracy of ``params`` on the full graph — the per-round
+    evaluation every training backend runs."""
+    logits = forward(params, data, data["nbr_mask"])
+    return (
+        masked_accuracy(logits, data["labels"], data["val_mask"]),
+        masked_accuracy(logits, data["labels"], data["test_mask"]),
+    )
 
 
 def make_local_update(loss_fn: Callable, cfg: FederatedConfig) -> Callable:
@@ -213,10 +247,10 @@ def make_local_update(loss_fn: Callable, cfg: FederatedConfig) -> Callable:
         make_dp_transform(priv, num_selected(cfg)) if priv.dp_enabled else None
     )
 
-    def local_update(gparams, opt_state, nb_mask, tr_mask, noise_key):
+    def local_update(gparams, opt_state, data, nb_mask, tr_mask, noise_key):
         def one(carry, _):
             params, opt = carry
-            grads = jax.grad(loss_fn)(params, nb_mask, tr_mask)
+            grads = jax.grad(loss_fn)(params, data, nb_mask, tr_mask)
             if cfg.aggregator == "fedprox":
                 grads = fedprox_grad(params, gparams, grads, cfg.prox_mu)
             params, opt = adam_update(
@@ -459,19 +493,17 @@ class Trainer:
         K = cfg.num_clients
 
         nb_masks, tr_masks = client_masks(cfg, g, part)
-        init_fn, forward = build_forward(cfg, g, k_pack)
+        init_fn, forward, data = build_forward(cfg, g, k_pack)
         global_params = init_fn(k_init)
-        labels = jnp.asarray(g.labels)
-        val_mask = jnp.asarray(g.val_mask)
-        test_mask = jnp.asarray(g.test_mask)
 
-        local_update = make_local_update(make_loss_fn(forward, labels), cfg)
+        local_update = make_local_update(make_loss_fn(forward), cfg)
         priv = cfg.privacy
         noise_base = noise_base_key(cfg.seed)
         mask_base = mask_base_key(cfg.seed)
 
         @jax.jit
-        def round_step(gparams, opt_states, server_state, chosen, sel_row, t):
+        def round_step(gparams, opt_states, server_state, data, nb_masks,
+                       tr_masks, chosen, sel_row, t):
             """chosen: (n_sel,) int — the clients CS(t) picked this round;
             sel_row: (K,) its 0/1 weight layout; t: round index (traced so
             every round shares one trace).
@@ -486,9 +518,9 @@ class Trainer:
             )
             noise_keys = jax.vmap(lambda c: client_round_key(noise_base, t, c))(chosen)
             stacked_params, sel_opt = jax.vmap(
-                local_update, in_axes=(None, 0, 0, 0, 0)
+                local_update, in_axes=(None, 0, None, 0, 0, 0)
             )(
-                gparams, sel_opt,
+                gparams, sel_opt, data,
                 jnp.take(nb_masks, chosen, axis=0),
                 jnp.take(tr_masks, chosen, axis=0),
                 noise_keys,
@@ -512,14 +544,7 @@ class Trainer:
                 new_global = fedavg(stacked_params)
             return new_global, opt_states, server_state
 
-        @jax.jit
-        def evaluate(params):
-            logits = forward(params, jnp.asarray(g.nbr_mask))
-            return (
-                masked_accuracy(logits, labels, val_mask),
-                masked_accuracy(logits, labels, test_mask),
-            )
-
+        evaluate = jax.jit(partial(accuracies, forward))
         opt_states = jax.vmap(lambda _: adam_init(global_params))(jnp.arange(K))
         server_state = adam_init(global_params)
 
@@ -532,13 +557,14 @@ class Trainer:
             with telemetry.span("round", round=t, backend="vmap"):
                 with telemetry.span("step", selected=int(sel_sched[t].sum())):
                     global_params, opt_states, server_state = round_step(
-                        global_params, opt_states, server_state,
+                        global_params, opt_states, server_state, data,
+                        nb_masks, tr_masks,
                         jnp.asarray(chosen_sched[t]),
                         jnp.asarray(sel_sched[t]),
                         jnp.asarray(t, jnp.int32),
                     )
                 with telemetry.span("evaluate"):
-                    va, ta = evaluate(global_params)
+                    va, ta = evaluate(global_params, data)
             val_curve.append(float(va))
             test_curve.append(float(ta))
             if traced and priv.dp_enabled:

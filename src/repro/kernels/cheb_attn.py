@@ -38,13 +38,29 @@ backward from the guarded oracle math).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 Array = jax.Array
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Per-call interpret-mode decision.
+
+    Priority: explicit argument > REPRO_PALLAS_INTERPRET env var ("1"/"0",
+    "true"/"false", ...) > backend default (interpret everywhere but TPU).
+    Resolved at call time so the backend may change after import.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip()
+    if env:  # empty counts as unset
+        return env.lower() not in ("0", "false", "no", "off")
+    return jax.default_backend() != "tpu"
 
 
 def _cheb_attn_kernel(x_ref, h_ref, m_ref, q_ref, o_ref):
@@ -77,7 +93,6 @@ def _cheb_attn_kernel(x_ref, h_ref, m_ref, q_ref, o_ref):
     o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
 def cheb_attn(
     x: Array,
     h_nb: Array,
@@ -86,7 +101,7 @@ def cheb_attn(
     *,
     block_n: int = 128,
     block_d: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """Fused polynomial-attention aggregation; one ``pallas_call`` total.
 
@@ -97,11 +112,19 @@ def cheb_attn(
       x: (G, H, N, B), h_nb: (G, N, B, D), mask: (G, N, B) -> (G, H, N, D)
 
     ``h_nb``/``mask`` are shared by all heads of a graph. Rows whose mask
-    sums to zero return exact zeros. interpret=True validates on CPU; on
-    TPU pass interpret=False.
+    sums to zero return exact zeros. ``interpret=None`` resolves through
+    :func:`resolve_interpret`: compiled on a TPU, interpreted elsewhere.
     """
+    return _cheb_attn(
+        x, h_nb, mask, coeffs, block_n=block_n, block_d=block_d,
+        interpret=resolve_interpret(interpret),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
+def _cheb_attn(x, h_nb, mask, coeffs, *, block_n, block_d, interpret):
     if x.ndim == 2:
-        return cheb_attn(
+        return _cheb_attn(
             x[None], h_nb, mask, coeffs,
             block_n=block_n, block_d=block_d, interpret=interpret,
         )[0]
@@ -165,7 +188,7 @@ def cheb_attn_diff(
     coeffs: Array,
     block_n: int = 128,
     block_d: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """(H, N, B) head-batched :func:`cheb_attn` that supports ``jax.grad``.
 

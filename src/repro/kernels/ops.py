@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cheb_attn import cheb_attn, cheb_attn_diff
+from repro.kernels.cheb_attn import cheb_attn, cheb_attn_diff, resolve_interpret
 from repro.kernels.flash_attn import flash_attn
 from repro.kernels.poly_attn import poly_attn
 from repro.kernels import ref
@@ -17,29 +17,19 @@ from repro.kernels import ref
 Array = jax.Array
 
 
-def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Per-call interpret-mode decision.
-
-    Priority: explicit argument > REPRO_PALLAS_INTERPRET env var ("1"/"0",
-    "true"/"false", ...) > backend default (interpret everywhere but TPU).
-    Resolved at call time so the backend may change after import.
-    """
-    if interpret is not None:
-        return bool(interpret)
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip()
-    if env:  # empty counts as unset
-        return env.lower() not in ("0", "false", "no", "off")
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # Block-size autotuning for cheb_attn
 # ---------------------------------------------------------------------------
 
 # Candidate tile edges: MXU/VPU-friendly powers of two down to the f32
-# sublane width. The layer pads N and D up to the chosen multiples, so any
-# candidate is legal for any shape.
+# sublane width. The layer pads N and D up to the chosen multiples. Every
+# block_n candidate is a multiple of 8, as Mosaic requires of a block's
+# second-to-last dim; the interpreter takes any block_d.
 _BLOCK_CANDIDATES = (128, 64, 32, 16, 8)
+# Compiled block_d candidates besides the whole feature width: Mosaic takes
+# a block's last dim only as a multiple of the 128-lane width or as the
+# whole array dim.
+_LANE_CANDIDATES = (512, 256, 128)
 # Per-block VMEM footprint budget (x + mask + h + out tiles, f32, double
 # buffered) — stay well under the ~16 MiB/core VMEM.
 _VMEM_BUDGET_BYTES = 4 * 1024 * 1024
@@ -57,7 +47,7 @@ def _pad_to(v: int, multiple: int) -> int:
 
 
 def select_block_sizes(
-    n: int, b: int, d: int, heads: int = 1, *, interpret: bool = True
+    n: int, b: int, d: int, heads: int = 1, *, interpret: Optional[bool] = None
 ) -> Tuple[int, int]:
     """Choose ``(block_n, block_d)`` for :func:`cheb_attn` given the shape.
 
@@ -65,8 +55,11 @@ def select_block_sizes(
     work (the layer pads N→block_n and D→block_d multiples, so oversized
     tiles waste compute) plus a per-grid-step launch overhead (weighted
     heavily in interpret mode), subject to a VMEM footprint budget.
-    Memoised per process; ``REPRO_CHEB_BLOCK_N`` / ``REPRO_CHEB_BLOCK_D``
-    env vars override either edge VERBATIM (validated as positive ints,
+    ``interpret=None`` resolves through :func:`resolve_interpret`.
+    Compiled, block_d is the whole width ``d`` or a multiple of 128, the
+    only tiles Mosaic lowers. A shape with no tile under the budget
+    raises. Memoised per process; ``REPRO_CHEB_BLOCK_N`` /
+    ``REPRO_CHEB_BLOCK_D`` env vars override either edge VERBATIM (validated as positive ints,
     but exempt from the VMEM budget and divisibility checks — the
     padding-layer consumer, :func:`cheb_attn_layer`, accepts any positive
     block; callers invoking :func:`cheb_attn` directly must snap the
@@ -84,17 +77,22 @@ def select_block_sizes(
             raise ValueError(f"{var}={raw!r}: must be a positive integer")
         return v
 
+    interpret = resolve_interpret(interpret)
     env_n = _env_block("REPRO_CHEB_BLOCK_N")
     env_d = _env_block("REPRO_CHEB_BLOCK_D")
-    key = (n, b, d, heads, bool(interpret), env_n, env_d)
+    key = (n, b, d, heads, interpret, env_n, env_d)
     hit = _BLOCK_CACHE.get(key)
     if hit is not None:
         return hit
 
-    overhead = _STEP_OVERHEAD[bool(interpret)]
+    overhead = _STEP_OVERHEAD[interpret]
+    d_candidates = (
+        _BLOCK_CANDIDATES if interpret
+        else sorted({d, *_LANE_CANDIDATES}, reverse=True)
+    )
     best, best_cost = None, None
     for bn in _BLOCK_CANDIDATES:
-        for bd in _BLOCK_CANDIDATES:
+        for bd in d_candidates:
             vmem = 4 * (bn * b          # x tile
                         + bn * b        # mask tile
                         + bn * b * bd   # h tile
@@ -111,11 +109,11 @@ def select_block_sizes(
             ):
                 best, best_cost = (bn, bd), cost
     if best is None:
-        # Degenerate padded degree (B > ~13k): even the smallest tile
-        # blows the VMEM budget. Fall back to it rather than refusing —
-        # in interpret mode it still runs; on real TPUs the pallas_call
-        # will surface the capacity error with the shape attached.
-        best = (min(_BLOCK_CANDIDATES), min(_BLOCK_CANDIDATES))
+        raise ValueError(
+            f"cheb_attn: no (block_n, block_d) tile for N={n}, B={b}, d={d}, "
+            f"heads={heads} fits the {_VMEM_BUDGET_BYTES} B VMEM budget "
+            f"(interpret={interpret})"
+        )
     if env_n is not None:
         best = (env_n, best[1])
     if env_d is not None:
@@ -179,6 +177,10 @@ def cheb_attn_layer(
     xp = jnp.pad(x, ((0, 0), (0, pad_n), (0, 0)))
     hp = jnp.pad(h_nb, ((0, pad_n), (0, 0), (0, pad_d)))
     mp = jnp.pad(mask_f, ((0, pad_n), (0, 0)))           # padded rows: den=0 -> 0
+    # Materialise the gathered neighbour features: left fusible, the gather
+    # meets the backward's contractions, and at 1e5 nodes the TPU compiler
+    # then spends many minutes on that fusion instead of ~20 s.
+    hp, mp = jax.lax.optimization_barrier((hp, mp))
 
     agg = cheb_attn_diff(
         xp, hp, mp, jnp.asarray(coeffs, jnp.float32),

@@ -12,7 +12,10 @@ Two halves, one env-var protocol:
 
 * **Launcher** (:func:`launch`): spawns N copies of a worker command on
   this host, each with ``REPRO_MP_*`` env vars carrying the coordinator
-  address, process id/count and forced host device count. It babysits the
+  address, process id/count and forced host device count, and with
+  ``JAX_PLATFORMS=cpu``: the workers simulate parties on host devices, and
+  on a machine with accelerators they must not each try to claim them. It
+  babysits the
   workers: the first non-zero exit reaps every sibling and becomes the
   launcher's own exit code; a wall-clock timeout bounds hangs; an
   explicitly requested coordinator port that is already bound is a clear
@@ -172,7 +175,8 @@ def launch(
     """Run ``cmd`` as ``processes`` cooperating workers; return an exit code.
 
     Each worker inherits this environment plus the ``REPRO_MP_*`` protocol
-    vars (:func:`initialize_worker` consumes them). Failure semantics:
+    vars (:func:`initialize_worker` consumes them), pinned to the CPU
+    backend with ``JAX_PLATFORMS=cpu``. Failure semantics:
 
     * any worker exiting non-zero reaps every sibling and its code is
       returned (the death of one SPMD participant deadlocks the rest at
@@ -206,6 +210,7 @@ def launch(
             wenv[ENV_PROCESS_ID] = str(i)
             wenv[ENV_DEVICES] = str(devices_per_process)
             wenv[ENV_INIT_TIMEOUT] = str(init_timeout)
+            wenv["JAX_PLATFORMS"] = "cpu"
             procs.append(subprocess.Popen(list(cmd), env=wenv))
         deadline = time.monotonic() + timeout
         while True:

@@ -153,8 +153,6 @@ def run_graph(argv=None) -> None:
     server = GraphInferenceServer.from_checkpoint(
         ckpt_dir, g, engine=args.engine, refresh_threshold=args.refresh_threshold,
     )
-    if server.engine_fallback:
-        print(f"engine fallback: {server.engine_fallback}")
     print(f"serving: engine={server.cfg.engine} method={server.method} "
           f"clients={server.num_clients} nodes={g.num_nodes}")
 
@@ -220,6 +218,9 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser(add_help=False)
     ap.add_argument("--mode", choices=("lm", "graph"), default="lm")
     args, rest = ap.parse_known_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     (run_graph if args.mode == "graph" else run_lm)(rest)
 
 

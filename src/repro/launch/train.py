@@ -117,6 +117,9 @@ def main() -> None:
     l.set_defaults(fn=run_lm)
 
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     args.fn(args)
 
 

@@ -142,8 +142,8 @@ def _trained_scores(g: Any, cfg: Any) -> Dict[str, np.ndarray]:
 
     res = Trainer(cfg).run(g)
     k_pack, _ = jax.random.split(jax.random.PRNGKey(cfg.seed))
-    _, forward = build_forward(cfg, g, k_pack)
-    logits = forward(res["params"], jnp.asarray(g.nbr_mask))
+    _, forward, data = build_forward(cfg, g, k_pack)
+    logits = forward(res["params"], data, data["nbr_mask"])
     scores = node_scores(logits, g.labels)
     scores["_result"] = res
     return scores

@@ -24,8 +24,6 @@ from repro.serving.server import (
     Query,
     QueryResult,
     client_pack_key,
-    kernel_available,
-    resolve_serving_engine,
 )
 from repro.serving.updates import (
     Coverage,
@@ -57,10 +55,8 @@ __all__ = [
     "extend_coverage",
     "graph_fingerprint",
     "initial_coverage",
-    "kernel_available",
     "load_bundle",
     "mass_drift",
     "patch_pack",
-    "resolve_serving_engine",
     "save_bundle",
 ]

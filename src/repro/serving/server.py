@@ -2,8 +2,8 @@
 
 The serving unit of work is one layered forward per (client, graph
 version): batched queries are grouped by client, each distinct client costs
-one engine forward (through the head-batched ``cheb_attn`` kernel when
-available), and per-query logits are gathered from it. Packs are cached
+one engine forward (through the head-batched ``cheb_attn`` kernel for the
+``kernel`` engine), and per-query logits are gathered from it. Packs are cached
 per client (:class:`~repro.serving.cache.PackCache`), graph deltas are
 absorbed with cheap local pack patches, and the accumulated drift is
 tracked against the paper's Thm 3.5 logit bound — a full per-client pack
@@ -52,26 +52,6 @@ class QueryResult(NamedTuple):
     node: int
     logits: np.ndarray      # (C,)
     label: int              # argmax class
-
-
-def kernel_available() -> bool:
-    """True when the Pallas kernel stack imports (jax.experimental.pallas
-    present and the kernels package loads)."""
-    try:
-        from repro.kernels import ops  # noqa: F401
-    except Exception:
-        return False
-    return True
-
-
-def resolve_serving_engine(name: str) -> Tuple[str, Optional[str]]:
-    """(engine to serve with, fallback note). The kernel engine degrades to
-    ``direct`` — the same numbers from per-edge math — when Pallas is
-    unavailable; every other engine must resolve or raise."""
-    get_engine(name)  # unknown names raise with the registry listing
-    if name == "kernel" and not kernel_available():
-        return "direct", "kernel engine unavailable (Pallas import failed); serving via 'direct'"
-    return name, None
 
 
 def client_pack_key(base_key: Array, client: int) -> Array:
@@ -128,10 +108,10 @@ class GraphInferenceServer:
             raise ValueError(f"num_clients must be >= 1, got {num_clients}")
         if refresh_threshold <= 0:
             raise ValueError(f"refresh_threshold must be > 0, got {refresh_threshold}")
-        requested = engine or model_cfg.engine
-        resolved, self.engine_fallback = resolve_serving_engine(requested)
-        self.cfg = replace(model_cfg, engine=resolved)
-        self.engine = get_engine(resolved)(self.cfg)
+        # The engine serves as requested or raises: an unknown name, or a
+        # kernel engine whose Pallas stack does not import.
+        self.cfg = replace(model_cfg, engine=engine or model_cfg.engine)
+        self.engine = get_engine(self.cfg.engine)(self.cfg)
         self.coeffs: Optional[Array] = (
             jnp.asarray(self.cfg.coeffs(), jnp.float32)
             if self.engine.needs_coeffs else None
@@ -451,7 +431,6 @@ class GraphInferenceServer:
     def stats(self) -> Dict[str, Any]:
         return {
             "engine": self.cfg.engine,
-            "engine_fallback": self.engine_fallback,
             "method": self.method,
             "num_clients": self.num_clients,
             "num_nodes": self.graph.num_nodes,

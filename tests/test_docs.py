@@ -15,7 +15,8 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 DOCS = REPO / "docs"
 CONFIG_MD = (DOCS / "configuration.md").read_text()
 
-_ENV_RE = re.compile(r"REPRO_[A-Z][A-Z0-9_]*[A-Z0-9]")
+# Whole names only: IS_REPRO_FALLBACK is a module attribute, not a variable.
+_ENV_RE = re.compile(r"(?<![A-Z0-9_])REPRO_[A-Z][A-Z0-9_]*[A-Z0-9]")
 
 
 def _source_env_vars():

@@ -18,7 +18,8 @@ import dataclasses
 SHAPE = dataclasses.replace(INPUT_SHAPES["train_4k"], seq_len=64, global_batch=4)
 DEC = dataclasses.replace(INPUT_SHAPES["decode_32k"], seq_len=128, global_batch=4)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 2)
 for arch in ("yi-6b", "granite-moe-1b-a400m", "rwkv6-1.6b", "hymba-1.5b",
              "paligemma-3b", "seamless-m4t-large-v2", "chatglm3-6b",
              "dbrx-132b", "qwen2-72b", "minitron-8b"):

@@ -232,10 +232,12 @@ def test_select_block_sizes_env_override(monkeypatch):
 
 
 def test_select_block_sizes_degenerate_degree_falls_back():
-    # B so large even the smallest (8, 8) tile blows the VMEM budget:
-    # the selector must fall back to that tile, not die in an assert.
+    # B so large even the smallest (8, 8) tile blows the VMEM budget: the
+    # selector refuses with the shape named instead of handing the kernel
+    # a tile that cannot fit.
     clear_block_cache()
-    assert select_block_sizes(64, 20_000, 32, interpret=True) == (8, 8)
+    with pytest.raises(ValueError, match="N=64, B=20000, d=32"):
+        select_block_sizes(64, 20_000, 32, interpret=True)
 
 
 @pytest.mark.parametrize("bad", ["0", "-8", "128k"])
@@ -247,10 +249,15 @@ def test_select_block_sizes_env_validation(monkeypatch, bad):
 
 
 def test_select_block_sizes_respects_vmem_budget():
-    # huge padded degree: the h tile (bn*b*bd*4 bytes) must stay under the
-    # budget, forcing small tiles rather than an OOM-sized block
-    bn, bd = select_block_sizes(4096, 2048, 4096, interpret=False)
-    assert 4 * bn * 2048 * bd <= 4 * 1024 * 1024
+    # large padded degree: the h tile (bn*b*bd*4 bytes) must stay under the
+    # budget, forcing small tiles rather than an OOM-sized block, and the
+    # tile stays one Mosaic lowers (bn % 8, bd % 128 or bd == d)
+    bn, bd = select_block_sizes(4096, 512, 4096, interpret=False)
+    assert 4 * bn * 512 * bd <= 4 * 1024 * 1024
+    assert bn % 8 == 0 and (bd % 128 == 0 or bd == 4096)
+    # no legal tile at all: refuse, naming the shape
+    with pytest.raises(ValueError, match="N=4096, B=2048, d=4096"):
+        select_block_sizes(4096, 2048, 4096, interpret=False)
 
 
 # ---------------------------------------------------------------------------

@@ -51,7 +51,8 @@ from repro.launch.dryrun import run_one
 
 # Reduced configs + scaled-down shapes so CPU compile stays fast; the
 # record schema is identical to the production dry-run's.
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 2)
 for arch, shape_name in (("yi-6b", "train_4k"), ("granite-moe-1b-a400m", "decode_32k")):
     shape = dataclasses.replace(INPUT_SHAPES[shape_name], seq_len=64, global_batch=4)
     rec = run_one(arch, shape_name, multi_pod=False,
@@ -89,3 +90,23 @@ def test_dryrun_records_schema():
         assert rec["model_flops_global"] > 0 and rec["model_flops_per_chip"] > 0
         assert rec["active_params"] > 0 and rec["total_params"] > 0
         json.dumps(rec)  # records must stay JSON-serialisable
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """Entry points leave a JAX_COMPILATION_CACHE_DIR set from outside to
+    JAX, and otherwise keep the cache at a fixed path in the checkout."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = str(ROOT / ".jax_compile_cache")
+        assert compile_cache.configure_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

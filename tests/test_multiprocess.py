@@ -122,6 +122,17 @@ def test_bound_coordinator_port_is_a_clear_error():
         assert time.monotonic() - t0 < 5
 
 
+def test_workers_are_pinned_to_cpu():
+    """Workers simulate parties on host devices: an inherited accelerator
+    platform must not reach them, or each would try to claim the chips."""
+    script = "import os, sys; sys.exit(0 if os.environ['JAX_PLATFORMS'] == 'cpu' else 3)"
+    code = mp.launch(
+        [sys.executable, "-c", script], processes=2, devices_per_process=1,
+        timeout=60, env={**os.environ, "JAX_PLATFORMS": "tpu"},
+    )
+    assert code == 0
+
+
 def test_launch_timeout_bounds_a_hung_gang():
     t0 = time.monotonic()
     code = mp.launch(

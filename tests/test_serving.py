@@ -2,6 +2,7 @@
 microbatching scheduler, and the serve benchmark contract."""
 import dataclasses
 import json
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,6 @@ from repro.serving import (
     client_pack_key,
     graph_fingerprint,
     load_bundle,
-    resolve_serving_engine,
     save_bundle,
 )
 
@@ -208,8 +208,7 @@ def trained_bundle(tiny, tmp_path_factory):
 def test_served_logits_match_model_apply(tiny, trained_bundle, engine):
     path, params = trained_bundle
     server = GraphInferenceServer.from_checkpoint(path, tiny, engine=engine)
-    resolved, _ = resolve_serving_engine(engine)
-    assert server.cfg.engine == resolved
+    assert server.cfg.engine == engine
     # loaded params are the trained ones
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(server.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -268,26 +267,25 @@ def test_distgat_requires_owners_for_new_nodes(tiny):
 
 
 # ---------------------------------------------------------------------------
-# Engine resolution / fallback
+# Engine resolution: serve as requested or raise
 # ---------------------------------------------------------------------------
 
-def test_kernel_fallback_when_pallas_missing(tiny, monkeypatch):
-    import repro.serving.server as srv_mod
+def test_kernel_request_raises_when_pallas_missing(tiny, monkeypatch):
+    """A kernel request never degrades to another engine: with the Pallas
+    stack unimportable, building the server raises."""
+    import repro.kernels as kernels_pkg
 
-    monkeypatch.setattr(srv_mod, "kernel_available", lambda: False)
-    assert srv_mod.resolve_serving_engine("kernel") == (
-        "direct", "kernel engine unavailable (Pallas import failed); serving via 'direct'"
-    )
-    cfg = FedGATConfig(engine="kernel")
+    monkeypatch.setitem(sys.modules, "repro.kernels.ops", None)
+    monkeypatch.delattr(kernels_pkg, "ops")
     params = FedGAT(FedGATConfig(engine="direct")).init(jax.random.PRNGKey(0), tiny)
-    server = GraphInferenceServer(params, cfg, tiny)
-    assert server.cfg.engine == "direct" and server.engine_fallback
-    server.serve_batch([Query(0, 0)])
+    with pytest.raises(ImportError):
+        GraphInferenceServer(params, FedGATConfig(engine="kernel"), tiny)
 
 
 def test_unknown_engine_raises(tiny):
+    params = FedGAT(FedGATConfig(engine="direct")).init(jax.random.PRNGKey(0), tiny)
     with pytest.raises(KeyError):
-        resolve_serving_engine("nonsense")
+        GraphInferenceServer(params, FedGATConfig(), tiny, engine="nonsense")
 
 
 # ---------------------------------------------------------------------------
