@@ -92,14 +92,18 @@ def layered_forward(
     Public building block: the serving layer calls it directly with cached
     (possibly patched) packs instead of going through a facade instance.
     """
-    x = engine.apply(params[0], pack, coeffs, h, nbr_idx, nbr_mask, concat=True)
-    x = elu(x)
+    # Each layer runs under a named scope ("layer1", "layer2", ...), so its
+    # device ops, forward and backward, carry the layer in their op names.
+    with jax.named_scope("layer1"):
+        x = engine.apply(params[0], pack, coeffs, h, nbr_idx, nbr_mask, concat=True)
+        x = elu(x)
     # Layers > 1: exact GAT update (paper: post-layer-1 embeddings shareable).
     for li in range(1, len(params)):
         last = li == len(params) - 1
-        x = gat_layer_nbr(params[li], x, nbr_idx, nbr_mask, concat=not last)
-        if not last:
-            x = elu(x)
+        with jax.named_scope(f"layer{li + 1}"):
+            x = gat_layer_nbr(params[li], x, nbr_idx, nbr_mask, concat=not last)
+            if not last:
+                x = elu(x)
     return x
 
 

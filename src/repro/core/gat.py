@@ -79,12 +79,14 @@ def gat_layer_nbr(params: Params, h: Array, nbr_idx: Array, nbr_mask: Array, con
     z = jnp.einsum("nd,hdo->hno", h, params["W"])           # (H, N, d_out)
     s1 = jnp.einsum("hno,ho->hn", z, params["a1"])          # (H, N)
     s2 = jnp.einsum("hno,ho->hn", z, params["a2"])          # (H, N)
-    s2_nb = s2[:, nbr_idx]                                   # (H, N, B)
+    with jax.named_scope("nbr_gather"):
+        s2_nb = s2[:, nbr_idx]                               # (H, N, B)
     logits = leaky_relu(s1[:, :, None] + s2_nb)              # (H, N, B)
     logits = jnp.where(nbr_mask[None], logits, -jnp.inf)
     alpha = jax.nn.softmax(logits, axis=-1)
     alpha = jnp.where(nbr_mask[None], alpha, 0.0)
-    z_nb = z[:, nbr_idx, :]                                  # (H, N, B, d_out)
+    with jax.named_scope("nbr_gather"):
+        z_nb = z[:, nbr_idx, :]                              # (H, N, B, d_out)
     out = jnp.einsum("hnb,hnbo->hno", alpha, z_nb)
     if concat:
         return jnp.transpose(out, (1, 0, 2)).reshape(h.shape[0], -1)

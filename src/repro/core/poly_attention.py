@@ -35,7 +35,9 @@ def edge_scores(b1: Array, b2: Array, h: Array, nbr_idx: Array) -> Array:
     """x_ij = b1.h_i + b2.h_j over padded neighbour lists. -> (H, N, B)."""
     s1 = jnp.einsum("nd,hd->hn", h, b1)
     s2 = jnp.einsum("nd,hd->hn", h, b2)
-    return s1[:, :, None] + s2[:, nbr_idx]
+    with jax.named_scope("nbr_gather"):
+        s2_nb = s2[:, nbr_idx]
+    return s1[:, :, None] + s2_nb
 
 
 def eval_series(coeffs: Array, x: Array, basis: str, domain: Tuple[float, float]) -> Array:
@@ -84,7 +86,9 @@ def poly_gat_layer(
     e = eval_series(coeffs, x, basis, domain)
     e = e * nbr_mask[None].astype(e.dtype)
     den = jnp.sum(e, axis=-1)[..., None]                     # (H, N, 1)
-    num = jnp.einsum("hnb,nbd->hnd", e, h[nbr_idx])          # (H, N, d_in)
+    with jax.named_scope("nbr_gather"):
+        h_nb = h[nbr_idx]                                    # (N, B, d_in)
+    num = jnp.einsum("hnb,nbd->hnd", e, h_nb)                # (H, N, d_in)
     # Isolated/fully-masked rows sum to exactly zero: aggregate to zero
     # instead of 0/0 NaN — the same guard as the kernel engine (ref.py),
     # keeping kernel/direct parity on degree-0 nodes.

@@ -49,7 +49,6 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict
-from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
@@ -247,13 +246,15 @@ def make_vmap_cohort_step(cfg, local_update: Callable, K: int) -> Callable:
             local_update,
             in_axes=(None, 0, None, 0 if per_client_nb else None, 0, 0),
         )(gparams, opt_slice, data, nb, tr, noise_keys)
-        if priv.secure_agg:
-            stacked = jax.vmap(
-                lambda p, c: add_client_mask(
-                    mask_base, t, c, sel_row, p, priv.mask_scale
-                )
-            )(stacked, ids)
-        return running_update(agg, stacked, w, scale=lam), new_opt
+        with jax.named_scope("fold"):
+            if priv.secure_agg:
+                stacked = jax.vmap(
+                    lambda p, c: add_client_mask(
+                        mask_base, t, c, sel_row, p, priv.mask_scale
+                    )
+                )(stacked, ids)
+            agg = running_update(agg, stacked, w, scale=lam)
+        return agg, new_opt
 
     return step
 
@@ -278,20 +279,21 @@ def make_shard_cohort_step(cfg, local_update: Callable, mesh, K: int) -> Callabl
         nbm = nb[0] if per_client_nb else nb
         noise_key = client_round_key(noise_base, t, cid)
         params, new_opt = local_update(gparams, opt1, data, nbm, tr[0], noise_key)
-        if priv.secure_agg:
-            params = add_client_mask(
-                mask_base, t, cid, sel_row, params, priv.mask_scale
+        with jax.named_scope("fold"):
+            if priv.secure_agg:
+                params = add_client_mask(
+                    mask_base, t, cid, sel_row, params, priv.mask_scale
+                )
+            cohort_sum = jax.tree.map(
+                lambda x: jax.lax.psum(wl.astype(x.dtype) * x, "lanes"), params
             )
-        cohort_sum = jax.tree.map(
-            lambda x: jax.lax.psum(wl.astype(x.dtype) * x, "lanes"), params
-        )
-        wsum = jax.lax.psum(wl, "lanes")
-        agg = RunningAggregate(
-            sum=jax.tree.map(
-                lambda a, s: a + lam.astype(a.dtype) * s, agg.sum, cohort_sum
-            ),
-            weight=agg.weight + lam * wsum,
-        )
+            wsum = jax.lax.psum(wl, "lanes")
+            agg = RunningAggregate(
+                sum=jax.tree.map(
+                    lambda a, s: a + lam.astype(a.dtype) * s, agg.sum, cohort_sum
+                ),
+                weight=agg.weight + lam * wsum,
+            )
         return agg, jax.tree.map(lambda x: x[None], new_opt)
 
     lanes = P("lanes")
@@ -440,9 +442,9 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
     cross-committed-device friction.
     """
     from repro.federated.trainer import (
-        accuracies,
         build_forward,
         build_result,
+        make_evaluate,
         make_local_update,
         make_loss_fn,
         num_selected,
@@ -517,10 +519,11 @@ def run_cohort_rounds(g: Graph, cfg, backend: str, mesh=None) -> Dict[str, Any]:
             else make_vmap_cohort_step(cfg, local_update, K)
         )
 
-    evaluate = jax.jit(partial(accuracies, forward))
-    server_apply = jax.jit(
-        lambda gp, mean, srv: fedadam_update(gp, mean, srv, cfg.server_lr)
-    )
+    evaluate = make_evaluate(forward)
+
+    @jax.jit
+    def server_apply(gp, mean, srv):
+        return fedadam_update(gp, mean, srv, cfg.server_lr)
 
     # Per-client optimizer bank: (K, ...) host numpy (zeros, matching the
     # legacy backends' stacked adam_init), scatter-updated cohort by cohort.

@@ -29,7 +29,6 @@ from __future__ import annotations
 import math
 import time
 from dataclasses import dataclass, field, replace
-from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -214,9 +213,9 @@ def make_loss_fn(forward: Callable) -> Callable:
     training labels under its edge-visibility mask."""
 
     def loss_fn(params, data, nb_mask, tr_mask):
-        return masked_cross_entropy(
-            forward(params, data, nb_mask), data["labels"], tr_mask
-        )
+        logits = forward(params, data, nb_mask)
+        with jax.named_scope("loss"):
+            return masked_cross_entropy(logits, data["labels"], tr_mask)
 
     return loss_fn
 
@@ -229,6 +228,16 @@ def accuracies(forward: Callable, params, data) -> Tuple[Array, Array]:
         masked_accuracy(logits, data["labels"], data["val_mask"]),
         masked_accuracy(logits, data["labels"], data["test_mask"]),
     )
+
+
+def make_evaluate(forward: Callable) -> Callable:
+    """:func:`accuracies` jitted as the program ``evaluate``."""
+
+    @jax.jit
+    def evaluate(params, data):
+        return accuracies(forward, params, data)
+
+    return evaluate
 
 
 def make_local_update(loss_fn: Callable, cfg: FederatedConfig) -> Callable:
@@ -251,11 +260,12 @@ def make_local_update(loss_fn: Callable, cfg: FederatedConfig) -> Callable:
         def one(carry, _):
             params, opt = carry
             grads = jax.grad(loss_fn)(params, data, nb_mask, tr_mask)
-            if cfg.aggregator == "fedprox":
-                grads = fedprox_grad(params, gparams, grads, cfg.prox_mu)
-            params, opt = adam_update(
-                grads, opt, params, cfg.lr, weight_decay=cfg.weight_decay
-            )
+            with jax.named_scope("adam"):
+                if cfg.aggregator == "fedprox":
+                    grads = fedprox_grad(params, gparams, grads, cfg.prox_mu)
+                params, opt = adam_update(
+                    grads, opt, params, cfg.lr, weight_decay=cfg.weight_decay
+                )
             return (params, opt), None
 
         (params, opt_state), _ = jax.lax.scan(
@@ -528,23 +538,25 @@ class Trainer:
             if priv.secure_agg:
                 # Each selected client ships a masked update; the pairwise
                 # masks cancel in the fedavg mean below (secure_agg.py).
-                stacked_params = jax.vmap(
-                    lambda p, c: add_client_mask(
-                        mask_base, t, c, sel_row, p, priv.mask_scale
-                    )
-                )(stacked_params, chosen)
+                with jax.named_scope("fold"):
+                    stacked_params = jax.vmap(
+                        lambda p, c: add_client_mask(
+                            mask_base, t, c, sel_row, p, priv.mask_scale
+                        )
+                    )(stacked_params, chosen)
             opt_states = jax.tree.map(
                 lambda full, new: full.at[chosen].set(new), opt_states, sel_opt
             )
-            if cfg.aggregator == "fedadam":
-                new_global, server_state = fedadam_server(
-                    gparams, stacked_params, server_state, cfg.server_lr
-                )
-            else:
-                new_global = fedavg(stacked_params)
+            with jax.named_scope("fold"):
+                if cfg.aggregator == "fedadam":
+                    new_global, server_state = fedadam_server(
+                        gparams, stacked_params, server_state, cfg.server_lr
+                    )
+                else:
+                    new_global = fedavg(stacked_params)
             return new_global, opt_states, server_state
 
-        evaluate = jax.jit(partial(accuracies, forward))
+        evaluate = make_evaluate(forward)
         opt_states = jax.vmap(lambda _: adam_init(global_params))(jnp.arange(K))
         server_state = adam_init(global_params)
 

@@ -164,7 +164,8 @@ def cheb_attn_layer(
     b1, b2 = head_projections(params)
     x = edge_scores(b1, b2, h, nbr_idx)                  # (H, N, B)
     mask_f = nbr_mask.astype(h.dtype)                    # (N, B)
-    h_nb = h[nbr_idx] * mask_f[..., None]                # (N, B, d)
+    with jax.named_scope("nbr_gather"):
+        h_nb = h[nbr_idx] * mask_f[..., None]            # (N, B, d)
 
     if block_n is None or block_d is None:
         auto_n, auto_d = select_block_sizes(
@@ -276,8 +277,10 @@ def cheb_attn_layer_bucketed(
     for rows, cap in plan:
         nb = nbr_idx[rows, :cap]                          # (n_k, cap)
         mask_f = jnp.asarray(nbr_mask[rows, :cap], h.dtype)
-        x = s1[:, rows, None] + s2[:, nb]                 # (H, n_k, cap)
-        h_nb = h[nb] * mask_f[..., None]                  # (n_k, cap, d)
+        with jax.named_scope("nbr_gather"):
+            s2_nb = s2[:, nb]
+            h_nb = h[nb] * mask_f[..., None]              # (n_k, cap, d)
+        x = s1[:, rows, None] + s2_nb                     # (H, n_k, cap)
 
         nk = len(rows)
         block_n, block_d = select_block_sizes(

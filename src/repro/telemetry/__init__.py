@@ -46,7 +46,7 @@ __all__ = [
     "manifest", "build_manifest", "config_hash",
     "jit_compile_count", "jit_compile_seconds", "install_jax_hooks",
     "export_chrome_trace", "write_run",
-    "SpanRecord", "Tracer", "EventSink", "NULL_SPAN",
+    "SpanRecord", "Tracer", "EventSink", "NULL_SPAN", "DEVICE_SCOPES",
 ]
 
 _enabled = False
@@ -152,6 +152,17 @@ def span(name: str, /, **args):
     if not _enabled:
         return NULL_SPAN
     return tracer.span(name, **args)
+
+
+# The ``jax.named_scope`` names the programs give their device work. They
+# change no computation: they land in each compiled op's ``op_name``
+# metadata, and so in a profiler trace's device ops, forward ops as
+# ``jvp(<scope>)`` or ``<scope>`` and their backward under ``transpose(``.
+# "layer1" is FedGAT's approximate layer and "layer<l>" each exact GAT
+# layer after it; "nbr_gather" the neighbour gathers inside them (their
+# backward is a scatter-add); "loss" the client loss; "adam" the client
+# optimizer step; "fold" the in-step FedAvg fold with its secure-agg masks.
+DEVICE_SCOPES = ("layer1", "layer2", "nbr_gather", "loss", "adam", "fold")
 
 
 def event(name: str, **fields) -> None:

@@ -3,6 +3,7 @@ when-disabled contract against the training/serving hot paths."""
 import json
 import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -192,6 +193,112 @@ def test_dp_run_records_epsilon_trajectory(graph):
     run_federated(graph, cfg)
     eps = telemetry.gauge("privacy.epsilon").value
     assert eps is not None and 0 < eps < math.inf
+
+
+# ---------------------------------------------------------------------------
+# Named device scopes: every part of the step's work carries its scope in
+# the compiled ops' op names, forward and (where differentiated) backward
+# ---------------------------------------------------------------------------
+
+# Scopes inside the client loss's gradient, so their backward exists.
+_DIFFERENTIATED = {"layer1", "layer2", "nbr_gather", "loss"}
+
+
+def _op_names(hlo_text):
+    return set(re.findall(r'op_name="([^"]*)"', hlo_text))
+
+
+def _scopes_of(op_name):
+    """(scope names along the op name's path, whether it is a backward op):
+    ``a/transpose(jvp(layer2))/nbr_gather/x`` -> ({"a", "layer2",
+    "nbr_gather", "x"}, True)."""
+    parts = op_name.split("/")
+    inner = {re.sub(r"^(?:[\w-]+\()+([^()]*)\)+$", r"\1", p) for p in parts}
+    return inner, any(p.startswith("transpose(") for p in parts)
+
+
+@pytest.fixture(scope="module")
+def scoped_programs(graph):
+    """Op names of the compiled cohort step and evaluate program of a small
+    FedGAT job on the ``kernel`` engine (Pallas interpreted on the CPU),
+    built as ``run_cohort_rounds`` builds them."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.federated import cohort, trainer
+    from repro.federated.aggregation import RunningAggregate
+    from repro.federated.partition import dirichlet_partition, stage_cohort_masks
+    from repro.optim.adamw import adam_init
+
+    cfg = FederatedConfig(
+        method="fedgat", num_clients=4, rounds=1, local_steps=1,
+        max_concurrent_clients=2, model=FedGATConfig(engine="kernel", degree=4),
+    )
+    K, lanes = cfg.num_clients, 2
+    k_pack, k_init = jax.random.split(jax.random.PRNGKey(cfg.seed))
+    init_fn, forward, data = trainer.build_forward(cfg, graph, k_pack)
+    params = init_fn(k_init)
+    part = dirichlet_partition(graph.labels, K, cfg.beta, cfg.seed)
+    _, tr = stage_cohort_masks(graph, part, (0, 1), lanes, neighbor=False)
+    step = cohort.make_vmap_cohort_step(
+        cfg, trainer.make_local_update(trainer.make_loss_fn(forward), cfg), K
+    )
+    agg = RunningAggregate(
+        sum=jax.tree.map(jnp.zeros_like, params), weight=jnp.zeros((), jnp.float32)
+    )
+    opt = jax.vmap(lambda _: adam_init(params))(jnp.arange(lanes))
+    step_text = step.lower(
+        params, agg, opt, data, data["nbr_mask"], tr,
+        jnp.arange(lanes, dtype=jnp.int32), jnp.ones(lanes, jnp.float32),
+        jnp.float32(1.0), jnp.zeros(K, jnp.float32), jnp.int32(0),
+    ).compile().as_text()
+    eval_text = trainer.make_evaluate(forward).lower(params, data).compile().as_text()
+    return {"step": step_text, "evaluate": eval_text}
+
+
+@pytest.mark.parametrize("scope", telemetry.DEVICE_SCOPES)
+def test_device_scope_names_the_steps_ops(scoped_programs, scope):
+    names = [n for n in _op_names(scoped_programs["step"]) if n.startswith("jit(step)/")]
+    found = [_scopes_of(n) for n in names if scope in _scopes_of(n)[0]]
+    assert any(not bwd for _, bwd in found), f"no forward op under {scope!r}"
+    if scope in _DIFFERENTIATED:
+        assert any(bwd for _, bwd in found), f"no backward op under {scope!r}"
+
+
+def test_evaluate_program_is_named_and_scoped(scoped_programs):
+    text = scoped_programs["evaluate"]
+    assert text.startswith("HloModule jit_evaluate")
+    assert "<unknown>" not in text
+    # (The interpreted Pallas body's loop carries relative names.)
+    paths = [n for n in _op_names(text) if n.startswith("jit(")]
+    assert paths and all(n.startswith("jit(evaluate)/") for n in paths)
+    scopes = set().union(*(_scopes_of(n)[0] for n in paths))
+    assert {"layer1", "layer2", "nbr_gather"} <= scopes
+
+
+def test_jitted_programs_compile_under_their_names(graph):
+    """A FedAdam cohort job compiles ``step``, ``evaluate`` and
+    ``server_apply``, and no program of the trainer is ``<unknown>``."""
+    from jax import monitoring
+
+    compiled = []
+
+    def on_duration(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    cfg = FederatedConfig(
+        method="fedgat", num_clients=4, rounds=1, local_steps=1,
+        max_concurrent_clients=2, aggregator="fedadam",
+        model=FedGATConfig(engine="kernel", degree=4),
+    )
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        run_federated(graph, cfg)
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert {"jit(step)", "jit(evaluate)", "jit(server_apply)"} <= set(compiled)
+    assert not {"jit(<unknown>)", "jit(<lambda>)"} & set(compiled), compiled
 
 
 # ---------------------------------------------------------------------------

@@ -124,3 +124,34 @@ def test_cheb_attn_vmapped_over_clients_compiles_for_v5e(one_chip):
     )
     text = _compiled_text(step, params, *graph)
     assert "tpu_custom_call" in text
+
+
+def test_kernel_keeps_its_name_under_the_layer_scope(one_chip, monkeypatch):
+    """Trained through the model, the kernel runs under the ``layer1`` named
+    scope; its custom call must still carry ``cheb_attn`` in its HLO name,
+    the name a profile's device ops are matched on."""
+    import re
+
+    from repro.core.engine import get_engine
+    from repro.core.fedgat_model import layered_forward
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    n, b, d, heads = 320, 16, 48, 8
+    cfg = FedGATConfig(engine="kernel", heads=heads, hidden=HIDDEN)
+    engine = get_engine("kernel")(cfg)
+    layer1, graph = _layer_args(one_chip, n, b, d, heads)
+    layer2 = {
+        "W": jax.ShapeDtypeStruct((1, heads * HIDDEN, 3), jnp.float32, sharding=one_chip),
+        "a1": jax.ShapeDtypeStruct((1, 3), jnp.float32, sharding=one_chip),
+        "a2": jax.ShapeDtypeStruct((1, 3), jnp.float32, sharding=one_chip),
+    }
+
+    def loss(params, coeffs, h, nbr_idx, nbr_mask):
+        return layered_forward(engine, params, coeffs, None, h, nbr_idx, nbr_mask).sum()
+
+    text = _compiled_text(jax.value_and_grad(loss), [layer1, layer2], *graph)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert "cheb_attn" in line.split(" = ", 1)[0], line[:200]
+        assert re.search(r'op_name="[^"]*layer1[^"]*"', line), line[:200]
