@@ -28,6 +28,7 @@ import jax
 from repro.core.fedgat_matrix import fedgat_layer_matrix, precompute_pack
 from repro.core.fedgat_vector import fedgat_layer_vector, precompute_vector_pack
 from repro.core.gat import gat_layer_nbr
+from repro.core.nbr_gather import NbrTable
 from repro.core.poly_attention import poly_gat_layer
 
 Array = jax.Array
@@ -118,7 +119,14 @@ class Engine:
         nbr_mask: Array,
         *,
         concat: bool = True,
+        table: Optional[NbrTable] = None,
     ) -> Array:
+        """Layer 1 under the edge-visibility mask ``nbr_mask``. ``table``
+        (the graph's lists) lets an engine that gathers neighbour scores
+        use :func:`~repro.core.nbr_gather.nbr_gather`'s gather backward:
+        ``kernel``, ``direct`` and ``exact`` do; ``matrix`` and ``vector``
+        read their pack and ignore it (the exact layers after layer 1
+        still take it, see ``layered_forward``)."""
         raise NotImplementedError
 
 
@@ -131,7 +139,7 @@ class MatrixEngine(Engine):
     def precompute(self, key, h, nbr_idx, nbr_mask):
         return precompute_pack(key, h, nbr_idx, nbr_mask, self.cfg.r)
 
-    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
+    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True, table=None):
         return fedgat_layer_matrix(
             params, pack, h, coeffs,
             basis=self.cfg.basis, domain=self.cfg.domain, concat=concat,
@@ -148,7 +156,7 @@ class VectorEngine(Engine):
     def precompute(self, key, h, nbr_idx, nbr_mask):
         return precompute_vector_pack(key, h, nbr_idx, nbr_mask)
 
-    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
+    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True, table=None):
         return fedgat_layer_vector(
             params, pack, h, coeffs,
             basis=self.cfg.basis, domain=self.cfg.domain, concat=concat,
@@ -159,10 +167,11 @@ class VectorEngine(Engine):
 class DirectEngine(Engine):
     """The mathematical oracle: same series, per-edge, no pack."""
 
-    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
+    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True, table=None):
         return poly_gat_layer(
             params, coeffs, h, nbr_idx, nbr_mask,
             basis=self.cfg.basis, domain=self.cfg.domain, concat=concat,
+            table=table,
         )
 
 
@@ -178,10 +187,11 @@ class KernelEngine(Engine):
 
         self._ops = ops
 
-    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
+    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True, table=None):
         return self._ops.cheb_attn_layer(
             params, coeffs, h, nbr_idx, nbr_mask,
             basis=self.cfg.basis, domain=self.cfg.domain, concat=concat,
+            table=table,
         )
 
 
@@ -192,5 +202,5 @@ class ExactEngine(Engine):
     needs_coeffs = False
     comm_cost_model = "none"  # no pack is communicated
 
-    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True):
-        return gat_layer_nbr(params, h, nbr_idx, nbr_mask, concat=concat)
+    def apply(self, params, pack, coeffs, h, nbr_idx, nbr_mask, *, concat=True, table=None):
+        return gat_layer_nbr(params, h, nbr_idx, nbr_mask, concat=concat, table=table)

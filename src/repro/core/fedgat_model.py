@@ -35,6 +35,7 @@ import numpy as np
 from repro.core import chebyshev
 from repro.core.engine import Engine, get_engine
 from repro.core.gat import elu, gat_layer_nbr, init_gat_params
+from repro.core.nbr_gather import NbrTable
 
 Array = jax.Array
 
@@ -86,22 +87,30 @@ def layered_forward(
     h: Array,
     nbr_idx: Array,
     nbr_mask: Array,
+    table: Optional[NbrTable] = None,
 ) -> Array:
     """Engine layer 1 + exact GAT layers l > 1 -> class logits (N, C).
 
     Public building block: the serving layer calls it directly with cached
     (possibly patched) packs instead of going through a facade instance.
+    ``table`` (the graph's lists with :func:`~repro.core.nbr_gather.
+    reverse_slots`) makes every neighbour-score and embedding gather's
+    backward a gather; without it autodiff's scatter-add stands.
     """
     # Each layer runs under a named scope ("layer1", "layer2", ...), so its
     # device ops, forward and backward, carry the layer in their op names.
     with jax.named_scope("layer1"):
-        x = engine.apply(params[0], pack, coeffs, h, nbr_idx, nbr_mask, concat=True)
+        x = engine.apply(
+            params[0], pack, coeffs, h, nbr_idx, nbr_mask, concat=True, table=table
+        )
         x = elu(x)
     # Layers > 1: exact GAT update (paper: post-layer-1 embeddings shareable).
     for li in range(1, len(params)):
         last = li == len(params) - 1
         with jax.named_scope(f"layer{li + 1}"):
-            x = gat_layer_nbr(params[li], x, nbr_idx, nbr_mask, concat=not last)
+            x = gat_layer_nbr(
+                params[li], x, nbr_idx, nbr_mask, concat=not last, table=table
+            )
             if not last:
                 x = elu(x)
     return x

@@ -11,10 +11,12 @@ Two equivalent forwards are provided:
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.nbr_gather import NbrTable, nbr_gather
 
 Array = jax.Array
 Params = Dict[str, Array]
@@ -74,19 +76,23 @@ def gat_layer_dense(params: Params, h: Array, adj: Array, concat: bool) -> Array
 # Neighbour-list forward (identical math; FedGAT's representation)
 # ---------------------------------------------------------------------------
 
-def gat_layer_nbr(params: Params, h: Array, nbr_idx: Array, nbr_mask: Array, concat: bool) -> Array:
-    """h: (N, d_in), nbr_idx/nbr_mask: (N, B)."""
+def gat_layer_nbr(
+    params: Params, h: Array, nbr_idx: Array, nbr_mask: Array, concat: bool,
+    table: Optional[NbrTable] = None,
+) -> Array:
+    """h: (N, d_in), nbr_idx/nbr_mask: (N, B); ``table`` (the graph's
+    lists, see :func:`~repro.core.nbr_gather.nbr_gather`) makes the
+    gathers' backward a gather."""
     z = jnp.einsum("nd,hdo->hno", h, params["W"])           # (H, N, d_out)
+    # The scores read the op's z, so z's gradient adds its slots onto theirs.
+    z, z_nb = nbr_gather(z, nbr_idx, table, axis=1, with_x=True)  # (H, N, B, d_out)
     s1 = jnp.einsum("hno,ho->hn", z, params["a1"])          # (H, N)
     s2 = jnp.einsum("hno,ho->hn", z, params["a2"])          # (H, N)
-    with jax.named_scope("nbr_gather"):
-        s2_nb = s2[:, nbr_idx]                               # (H, N, B)
+    s2_nb = nbr_gather(s2, nbr_idx, table, axis=1)           # (H, N, B)
     logits = leaky_relu(s1[:, :, None] + s2_nb)              # (H, N, B)
     logits = jnp.where(nbr_mask[None], logits, -jnp.inf)
     alpha = jax.nn.softmax(logits, axis=-1)
     alpha = jnp.where(nbr_mask[None], alpha, 0.0)
-    with jax.named_scope("nbr_gather"):
-        z_nb = z[:, nbr_idx, :]                              # (H, N, B, d_out)
     out = jnp.einsum("hnb,hnbo->hno", alpha, z_nb)
     if concat:
         return jnp.transpose(out, (1, 0, 2)).reshape(h.shape[0], -1)

@@ -13,12 +13,13 @@ matrices. It is:
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.chebyshev import eval_chebyshev, eval_power_series
+from repro.core.nbr_gather import NbrTable, nbr_gather
 
 Array = jax.Array
 Params = Dict[str, Array]
@@ -31,13 +32,16 @@ def head_projections(params: Params) -> Tuple[Array, Array]:
     return b1, b2
 
 
-def edge_scores(b1: Array, b2: Array, h: Array, nbr_idx: Array) -> Array:
-    """x_ij = b1.h_i + b2.h_j over padded neighbour lists. -> (H, N, B)."""
+def edge_scores(
+    b1: Array, b2: Array, h: Array, nbr_idx: Array, table: Optional[NbrTable] = None
+) -> Array:
+    """x_ij = b1.h_i + b2.h_j over padded neighbour lists. -> (H, N, B).
+
+    With ``table`` (:func:`~repro.core.nbr_gather.nbr_gather`) the graph's
+    padding slots read s1 alone, and the gather's backward is a gather."""
     s1 = jnp.einsum("nd,hd->hn", h, b1)
     s2 = jnp.einsum("nd,hd->hn", h, b2)
-    with jax.named_scope("nbr_gather"):
-        s2_nb = s2[:, nbr_idx]
-    return s1[:, :, None] + s2_nb
+    return s1[:, :, None] + nbr_gather(s2, nbr_idx, table, axis=1)
 
 
 def eval_series(coeffs: Array, x: Array, basis: str, domain: Tuple[float, float]) -> Array:
@@ -75,6 +79,7 @@ def poly_gat_layer(
     basis: str = "power",
     domain: Tuple[float, float] = (-4.0, 4.0),
     concat: bool = True,
+    table: Optional[NbrTable] = None,
 ) -> Array:
     """Approximate GAT layer via the truncated series (paper Eq. 7).
 
@@ -82,7 +87,7 @@ def poly_gat_layer(
     pre-communicated pack. h: (N, d_in) -> (N, H*d_out) or (N, d_out).
     """
     b1, b2 = head_projections(params)
-    x = edge_scores(b1, b2, h, nbr_idx)                      # (H, N, B)
+    x = edge_scores(b1, b2, h, nbr_idx, table)               # (H, N, B)
     e = eval_series(coeffs, x, basis, domain)
     e = e * nbr_mask[None].astype(e.dtype)
     den = jnp.sum(e, axis=-1)[..., None]                     # (H, N, 1)

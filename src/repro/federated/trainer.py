@@ -39,6 +39,7 @@ from repro import telemetry
 from repro.core.fedgat_model import FedGAT, FedGATConfig, layered_forward
 from repro.core.gat import masked_accuracy, masked_cross_entropy
 from repro.core.gcn import gcn_forward_nbr, init_gcn_params, normalized_nbr_coeffs
+from repro.core.nbr_gather import NbrTable, reverse_slots
 from repro.federated import comm as comm_mod
 from repro.federated.aggregation import fedadam_server, fedavg, fedprox_grad
 from repro.federated.partition import (
@@ -141,7 +142,8 @@ def build_forward(
     """Returns (init_fn, forward(params, data, nbr_mask) -> logits, data).
 
     ``data`` is :func:`graph_data` plus what the method's forward reads
-    (series coefficients and the one-shot pack, or the GCN coefficients).
+    (series coefficients, the one-shot pack and the graph's reverse-slot
+    tables ``nbr_table``, or the GCN coefficients).
     Every jitted step takes it as an argument: closed over, the graph would
     be baked into each compiled program as constants, which at 1e5 nodes
     makes compiles take minutes.
@@ -171,7 +173,13 @@ def build_forward(
                 data["h"], cfg.privacy.pack_noise_multiplier,
                 granularity=granularity, node_influence=influence,
             )
-        data.update(coeffs=model.coeffs, pack=model.pack)
+        # The reverse-slot tables of the graph's concrete lists: with them
+        # the neighbour gathers' backward is a gather, not a scatter-add.
+        rev, overflow = reverse_slots(g.nbr_idx, g.nbr_mask)
+        data.update(
+            coeffs=model.coeffs, pack=model.pack,
+            nbr_table=NbrTable(data["nbr_mask"], jnp.asarray(rev), jnp.asarray(overflow)),
+        )
 
         def init_fn(k):
             return model.init(k, g)
@@ -179,7 +187,7 @@ def build_forward(
         def forward(params, data, nb_mask):
             return layered_forward(
                 model.engine, params, data["coeffs"], data["pack"],
-                data["h"], data["nbr_idx"], nb_mask,
+                data["h"], data["nbr_idx"], nb_mask, data["nbr_table"],
             )
 
         return init_fn, forward, data

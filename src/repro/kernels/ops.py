@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.nbr_gather import NbrTable
 from repro.kernels.cheb_attn import cheb_attn, cheb_attn_diff, resolve_interpret
 from repro.kernels.flash_attn import flash_attn
 from repro.kernels.poly_attn import poly_attn
@@ -144,6 +145,7 @@ def cheb_attn_layer(
     interpret: Optional[bool] = None,
     block_n: Optional[int] = None,
     block_d: Optional[int] = None,
+    table: Optional[NbrTable] = None,
 ) -> Array:
     """FedGAT layer-1 via the fused Pallas kernel ("kernel" engine).
 
@@ -153,7 +155,8 @@ def cheb_attn_layer(
     numerically the direct oracle (ref.py). Differentiable: the forward is
     the kernel, the backward is the guarded oracle math (``custom_vjp``).
     Padding rows are fully masked and come out as exact zeros (no fake
-    neighbours needed), as do genuinely isolated nodes.
+    neighbours needed), as do genuinely isolated nodes. ``table`` goes to
+    :func:`~repro.core.poly_attention.edge_scores`.
     """
     if basis != "power":
         raise ValueError("kernel engine evaluates the monomial (power) basis")
@@ -162,7 +165,7 @@ def cheb_attn_layer(
     interp = resolve_interpret(interpret)
     n, d = h.shape
     b1, b2 = head_projections(params)
-    x = edge_scores(b1, b2, h, nbr_idx)                  # (H, N, B)
+    x = edge_scores(b1, b2, h, nbr_idx, table)           # (H, N, B)
     mask_f = nbr_mask.astype(h.dtype)                    # (N, B)
     with jax.named_scope("nbr_gather"):
         h_nb = h[nbr_idx] * mask_f[..., None]            # (N, B, d)

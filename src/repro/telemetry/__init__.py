@@ -160,7 +160,8 @@ def span(name: str, /, **args):
 # ``jvp(<scope>)`` or ``<scope>`` and their backward under ``transpose(``.
 # "layer1" is FedGAT's approximate layer and "layer<l>" each exact GAT
 # layer after it; "nbr_gather" the neighbour gathers inside them (their
-# backward is a scatter-add); "loss" the client loss; "adam" the client
+# backward a gather with the graph's reverse-slot table, a scatter-add
+# without; repro.core.nbr_gather); "loss" the client loss; "adam" the client
 # optimizer step; "fold" the in-step FedAvg fold with its secure-agg masks.
 DEVICE_SCOPES = ("layer1", "layer2", "nbr_gather", "loss", "adam", "fold")
 

@@ -155,3 +155,39 @@ def test_kernel_keeps_its_name_under_the_layer_scope(one_chip, monkeypatch):
     for line in calls:
         assert "cheb_attn" in line.split(" = ", 1)[0], line[:200]
         assert re.search(r'op_name="[^"]*layer1[^"]*"', line), line[:200]
+
+
+def test_neighbour_gather_backward_has_no_scatter_on_v5e(one_chip, monkeypatch):
+    """Compiled for the chip, the kernel engine's client gradient with the
+    graph's reverse-slot tables keeps no scatter under ``nbr_gather``."""
+    import re
+
+    from repro.core.engine import get_engine
+    from repro.core.fedgat_model import layered_forward
+    from repro.core.nbr_gather import NbrTable, reverse_slots
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    n, b, d, heads = 320, 16, 48, 8
+    rng = np.random.default_rng(0)
+    nbr_idx = rng.integers(0, n, (n, b)).astype(np.int32)
+    nbr_mask = rng.random((n, b)) < 0.4
+    rev, overflow = reverse_slots(nbr_idx, nbr_mask)
+    cfg = FedGATConfig(engine="kernel", heads=heads, hidden=HIDDEN, out_heads=heads)
+    engine = get_engine("kernel")(cfg)
+    layer1, graph = _layer_args(one_chip, n, b, d, heads)
+    sds = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    layer2 = {
+        "W": sds((heads, heads * HIDDEN, 3), jnp.float32),
+        "a1": sds((heads, 3), jnp.float32),
+        "a2": sds((heads, 3), jnp.float32),
+    }
+    table = NbrTable(sds((n, b), jnp.bool_), sds(rev.shape, jnp.int32), sds(overflow.shape, jnp.int32))
+
+    def loss(params, coeffs, h, nbr_idx, nbr_mask, table):
+        return layered_forward(engine, params, coeffs, None, h, nbr_idx, nbr_mask, table).sum()
+
+    text = _compiled_text(jax.grad(loss), [layer1, layer2], *graph, table)
+    names = [re.search(r'op_name="([^"]*)"', line) for line in text.splitlines()
+             if re.search(r"= \S+ scatter\(", line)]
+    assert not [m.group(1) for m in names if m and "nbr_gather" in m.group(1)]
+    assert re.search(r'op_name="[^"]*transpose\(jvp\(layer2\)\)/nbr_gather/[^"]*gather', text)
